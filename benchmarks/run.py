@@ -394,7 +394,7 @@ def bench_cascade(res):
     wrong.  Cascade must strictly dominate at least one single-shot
     point (>= accuracy at <= mean size, strict in one coordinate) —
     a generator so every measured row is emitted before the gate
-    raises; under --strict a non-dominating front fails the run.
+    raises; a non-dominating front fails the run.
     """
     from repro.core import experiment as ex
     from repro.core.objective import recency_constraint, size_constraint
@@ -978,7 +978,7 @@ def bench_cache(res):
     verdicts of the unique prefix (half the smallest distance between
     any two disagreeing prompts).
 
-    Gates (--strict fails the run):
+    Gates (a failed gate fails the run):
       * combined T1+T2+T3 hit-rate >= 2x the exact-only engine's on the
         identical stream;
       * zero wrong routings: every expert choice the tiered engine
@@ -1311,11 +1311,10 @@ def main(argv=None) -> None:
                     help="smaller fallback experiment when artifacts are "
                          "missing; self-scaling benchmarks (mesh) also "
                          "shrink")
-    ap.add_argument("--strict", action="store_true",
-                    help="exit non-zero if any selected benchmark errors "
-                         "(CI smoke mode)")
     args = ap.parse_args(argv)
     _FAST["fast"] = args.fast
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     selected = [x.strip() for x in args.only.split(",") if x.strip()]
     unknown = set(selected) - {name for name, _, _ in BENCHES}
@@ -1347,7 +1346,7 @@ def main(argv=None) -> None:
     if args.out:
         with open(args.out, "w") as f:
             f.write("\n".join(lines) + "\n")
-    if args.strict and errors:
+    if errors:
         raise SystemExit(f"{errors} benchmark(s) errored")
 
 

@@ -107,3 +107,21 @@ class ModelLibrary:
                 e.n_params = n_params
                 return
         raise KeyError(name)
+
+
+def seeded_library(specs: list[ExpertSpec], seed: int) -> ModelLibrary:
+    """A library over ``specs`` with random weights drawn from ``seed``
+    (expert i from the i-th split of the seed's key).  Routing, the
+    cascade and expert execution run the same programs on random
+    weights as on trained ones, so a serving smoke run needs no
+    training and reads no artifacts."""
+    import jax
+
+    from repro.core.rngs import seeded_key
+    from repro.models.model import count_params, init_model
+
+    keys = jax.random.split(seeded_key(seed), len(specs))
+    for k, e in zip(keys, specs):
+        e.params, _ = init_model(k, e.cfg)
+        e.n_params = count_params(e.params)
+    return ModelLibrary(specs)

@@ -35,10 +35,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, window,
         m, l, acc = carry
         # leading dim indexed with pl.ds(0, 1), not a python int: interpret
         # mode's load discharge rejects scalar int indices inside fori_loop
-        k = pl.load(k_ref, (pl.ds(0, 1), pl.ds(kb * block_k, block_k),
-                            slice(None)))[0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.ds(0, 1), pl.ds(kb * block_k, block_k),
-                            slice(None)))[0].astype(jnp.float32)
+        kv = (pl.ds(0, 1), pl.ds(kb * block_k, block_k), slice(None))
+        k = k_ref[kv][0].astype(jnp.float32)
+        v = v_ref[kv][0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (bq, bk)
         if softcap > 0:
             s = softcap * jnp.tanh(s / softcap)

@@ -69,7 +69,9 @@ def _cascade_kernel(emb_ref, w1_ref, b1_ref, w2_ref, b2_ref,
     combined = pred + jax.lax.dot_general(
         lam_ref[...].astype(jnp.float32), cvals_ref[...],
         (((1,), (0,)), ((), ())))
-    choice = jnp.argmin(combined, axis=1).astype(jnp.int32)
+    # (bb, 1) columns throughout: a 1-D (bb,) output block mismatches
+    # XLA's tiling of the s32[Bp] result once the grid has several tiles
+    choice = jnp.argmin(combined, axis=1, keepdims=True).astype(jnp.int32)
     choice_ref[...] = choice
     # depth-1 escalation: re-argmin over experts strictly later in the
     # escalation ladder than the first pick.  pos_ref holds each
@@ -79,20 +81,19 @@ def _cascade_kernel(emb_ref, w1_ref, b1_ref, w2_ref, b2_ref,
     pos = pos_ref[...].astype(jnp.int32)[None, :]        # (1, M)
     # ladder position of each row's first pick, via one-hot contraction
     # (gathers are awkward on the TPU vector unit; M is tiny)
-    pos_choice = jnp.sum(
-        jnp.where(ids == choice[:, None], pos, 0), axis=1)  # (bb,)
-    above = pos > pos_choice[:, None]                    # (bb, M)
+    pos_choice = jnp.sum(jnp.where(ids == choice, pos, 0), axis=1,
+                         keepdims=True)                  # (bb, 1)
+    above = pos > pos_choice                             # (bb, M)
     big = jnp.full_like(combined, jnp.inf)
     masked = jnp.where(above, combined, big)
     minval = jnp.min(masked, axis=1, keepdims=True)
     # tie-break to the earliest ladder rung among the argmin set — the
     # host walk's np.argmin over order[pos+1:] (first occurrence) exactly
     cand_pos = jnp.where(masked == minval, pos, jnp.int32(M))
-    best_pos = jnp.min(cand_pos, axis=1)                 # (bb,)
-    esc = jnp.sum(jnp.where(pos == best_pos[:, None], ids, 0), axis=1)
-    has_next = above.any(axis=1)
+    best_pos = jnp.min(cand_pos, axis=1, keepdims=True)  # (bb, 1)
+    esc = jnp.sum(jnp.where(pos == best_pos, ids, 0), axis=1, keepdims=True)
+    has_next = jnp.any(above, axis=1, keepdims=True)
     esc_ref[...] = jnp.where(has_next, esc, choice).astype(jnp.int32)
-
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def router_score_cascade_fused(emb, w1, b1, w2, b2, uw1, ub1, uw2, ub2,
@@ -139,15 +140,15 @@ def router_score_cascade_fused(emb, w1, b1, w2, b2, uw1, ub1, uw2, ub2,
         out_specs=[
             pl.BlockSpec((block_b, M), lambda i: (i, 0)),
             pl.BlockSpec((block_b, M), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bp, M), jnp.float32),
             jax.ShapeDtypeStruct((Bp, M), jnp.float32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
         ],
         interpret=interpret,
     )(emb, w1, b1, w2, b2, uw1, ub1, uw2, ub2, cvals, lam, ladder_pos)
-    return pred[:B], sigma[:B], choice[:B], esc[:B]
+    return pred[:B], sigma[:B], choice[:B, 0], esc[:B, 0]

@@ -35,7 +35,10 @@ def _router_kernel(emb_ref, w1_ref, b1_ref, w2_ref, b2_ref, cvals_ref,
     combined = pred + jax.lax.dot_general(
         lam_ref[...].astype(jnp.float32), cvals_ref[...],
         (((1,), (0,)), ((), ())))
-    choice_ref[...] = jnp.argmin(combined, axis=1).astype(jnp.int32)
+    # (bb, 1) column: a 1-D (bb,) output block mismatches XLA's tiling of
+    # the s32[Bp] result once the grid has several tiles
+    choice_ref[...] = jnp.argmin(combined, axis=1,
+                                 keepdims=True).astype(jnp.int32)
 
 
 def launch_plan(B: int, block_b: int) -> dict:
@@ -89,12 +92,12 @@ def router_score_fused(emb, w1, b1, w2, b2, cvals, lam, *, block_b=128,
         ],
         out_specs=[
             pl.BlockSpec((block_b, M), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bp, M), jnp.float32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
         ],
         interpret=interpret,
     )(emb, w1, b1, w2, b2, cvals, lam)
-    return scores[:B], choice[:B]
+    return scores[:B], choice[:B, 0]

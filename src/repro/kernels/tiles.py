@@ -5,9 +5,9 @@ batch) and persists the winners to ``experiments/tryage/tile_table.json``
 (override with the ``REPRO_TILE_TABLE`` env var or ``set_table_path``,
 e.g. from ``launch/serve.py --tile-table``).  The kernel ops wrappers
 call ``tile_for`` when the caller left the tile argument at ``None``:
-a missing/unreadable table, an unknown kernel, or a foreign backend all
-fall back to the static default — consultation can *never* raise, and a
-caller who passes an explicit tile is never second-guessed.
+a missing/unreadable table, an unknown kernel, or a backend the table
+does not list all fall back to the static default, and a caller who
+passes an explicit tile is never second-guessed.
 
 Table schema (see ``launch.autotune.write_table``)::
 
@@ -77,11 +77,8 @@ def load_table(path: str | None = None) -> dict | None:
 
 
 def _backend() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:                                  # pragma: no cover
-        return "cpu"
+    import jax
+    return jax.default_backend()
 
 
 def tile_for(kernel: str, batch: int, param: str, default: int,

@@ -19,6 +19,14 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes: the serving and dry-run paths
+    place arrays with ``NamedSharding`` and let GSPMD propagate, which
+    the default Explicit axes refuse (``ShardingTypeError`` on gathers)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _require_devices(shape: tuple[int, ...], axes: tuple[str, ...]):
@@ -38,7 +46,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     _require_devices(shape, axes)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -49,4 +57,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
                          f"data={data} model={model}")
     shape, axes = (data, model), ("data", "model")
     _require_devices(shape, axes)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)

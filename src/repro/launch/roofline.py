@@ -6,9 +6,9 @@
 
 All inputs are per-chip (cost_analysis and the parsed HLO are post-SPMD).
 Hardware constants come from a preset table (``PRESETS``) selected
-explicitly or by backend detection (``detect_preset``); the default stays
-TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI — so
-existing callers are unchanged.
+explicitly or by backend detection (``detect_preset``, TPUs keyed by
+``device_kind``); the default stays TPU v5e — 197 TFLOP/s bf16,
+819 GB/s HBM, ~50 GB/s/link ICI — so existing callers are unchanged.
 """
 
 from __future__ import annotations
@@ -48,20 +48,30 @@ ICI_BW = _DEFAULT.ici_bw
 ICI_LINKS = _DEFAULT.ici_links
 
 
-def detect_preset() -> HWPreset:
-    """The preset matching the live JAX backend (``tpu`` -> tpu-v5e,
-    ``gpu``/``cuda``/``rocm`` -> gpu, anything else -> cpu).  Lazy
-    import: the module stays importable without a working backend."""
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:
-        return PRESETS["cpu"]
+# TPU peaks keyed by the ``device_kind`` JAX reports for the chip
+TPU_KINDS = {"TPU v5 lite": "tpu-v5e"}
+
+
+def preset_for(backend: str, device_kind: str) -> HWPreset:
+    """The preset of a backend and device kind: a TPU by its kind (an
+    unknown TPU kind is an error, not a default), ``gpu``/``cuda``/
+    ``rocm`` -> gpu, ``cpu`` -> cpu."""
     if backend == "tpu":
-        return PRESETS["tpu-v5e"]
+        if device_kind not in TPU_KINDS:
+            raise ValueError(f"no roofline preset for TPU kind "
+                             f"{device_kind!r} (known: {sorted(TPU_KINDS)})")
+        return PRESETS[TPU_KINDS[device_kind]]
     if backend in ("gpu", "cuda", "rocm"):
         return PRESETS["gpu"]
-    return PRESETS["cpu"]
+    if backend == "cpu":
+        return PRESETS["cpu"]
+    raise ValueError(f"no roofline preset for backend {backend!r}")
+
+
+def detect_preset() -> HWPreset:
+    """The preset of the live JAX backend's first device."""
+    import jax
+    return preset_for(jax.default_backend(), jax.devices()[0].device_kind)
 
 
 def resolve_preset(name: str | None) -> HWPreset:

@@ -87,6 +87,7 @@ placement and per-stream busy times.  On CPU, simulate devices with
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
@@ -123,7 +124,10 @@ def parse_priority_mix(spec: str) -> list[float]:
     return [f / total for f in fracs]
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and cross-validate the serving flags.  Adds ``tiers`` (the
+    live decision-cache tiers) and ``mesh_shape`` ((data, model) or
+    None) to the namespace."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=256)
     ap.add_argument("--seq", type=int, default=128)
@@ -235,7 +239,7 @@ def main():
                     help="enable the checkify sanitizer (NaN/inf + OOB "
                          "checks on the routing path; same switch as "
                          "REPRO_SANITIZE=1)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.adapt_every > 0 and args.replay_cap <= 0:
         ap.error("--adapt-every needs a replay buffer (--replay-cap >= 1)")
     tiers = {t.strip() for t in args.cache_tiers.split(",") if t.strip()}
@@ -250,6 +254,7 @@ def main():
     if args.no_cache and tiers - {"exact"}:
         ap.error("--no-cache conflicts with --cache-tiers "
                  "persistent/semantic")
+    args.tiers = tiers
 
     if args.fused_cascade and not args.use_kernel:
         ap.error("--fused-cascade needs --use-kernel")
@@ -263,49 +268,49 @@ def main():
                  "cannot reorder around the health consult")
     if args.speculate and args.fifo:
         ap.error("--speculate needs the scheduler (drop --fifo)")
+    if args.fail_expert and args.fifo:
+        ap.error("--fail-expert needs the scheduler (drop --fifo)")
+    if args.sessions > 0 and args.fifo:
+        ap.error("--sessions needs the streaming engine (drop --fifo)")
 
-    if args.tile_table:
-        from repro.kernels import tiles
-        tiles.set_table_path(args.tile_table)
+    args.mesh_shape = None
+    if args.mesh:
+        try:
+            args.mesh_shape = tuple(int(x) for x in args.mesh.split(","))
+        except ValueError:
+            ap.error("--mesh expects two integers 'data,model'")
+        if len(args.mesh_shape) != 2:
+            ap.error("--mesh expects two integers 'data,model'")
+    elif args.replicate_hot:
+        ap.error("--replicate-hot needs --mesh")
+    return args
 
-    if args.sanitize:
-        from repro.kernels import sanitize
-        sanitize.set_sanitize(True)
 
-    from repro.core import experiment as ex
+@dataclasses.dataclass
+class ServeRun:
+    engine: object
+    requests: list
+    results: list
+    summary: dict
+
+
+def serve_library(lib, rp, rc, corpus, args, placement=None) -> ServeRun:
+    """One serving run over a library and router: build the engine the
+    flags describe, sample ``--requests`` requests from ``corpus`` and
+    serve them.  ``main`` calls it with the trained artifacts;
+    ``chip_smoke.py`` with seeded models.  ``placement`` overrides the
+    default size-balanced plan under ``--mesh`` (e.g. a traffic-aware
+    one)."""
     from repro.core.objective import recency_constraint, size_constraint
     from repro.data.batching import mlm_batch
     from repro.serving import (ExpertHealth, Request, ServingFrontend,
                                Session, TryageEngine)
     from repro.serving.metrics import render, start_metrics_server
 
-    try:
-        art = ex.load_artifacts()
-    except FileNotFoundError:
-        print("no artifacts; running reduced experiment first", flush=True)
-        xc = ex.ExperimentConfig(expert_steps=60, n_train_prompts=512,
-                                 n_val_prompts=128, n_test_per_domain=24,
-                                 router_epochs=3)
-        ex.run_experiment(xc, verbose=True)
-        art = ex.load_artifacts()
-
-    lib, rp, rc, corpus = (art["library"], art["router_params"], art["rc"],
-                           art["corpus"])
-    if args.cascade > 0 and "unc" not in rp:
-        from repro.core.training import calibrate_uncertainty
-        print("calibrating uncertainty head on held-out Q-table", flush=True)
-        rp = calibrate_uncertainty(rp, rc, art["test_tokens"],
-                                   art["q_test"]["loss"])
     mesh = None
-    if args.mesh:
+    if args.mesh_shape is not None:
         from repro.launch.mesh import make_host_mesh
-        try:
-            mdata, mmodel = (int(x) for x in args.mesh.split(","))
-        except ValueError:
-            ap.error("--mesh expects two integers 'data,model'")
-        mesh = make_host_mesh(mdata, mmodel)
-    elif args.replicate_hot:
-        ap.error("--replicate-hot needs --mesh")
+        mesh = make_host_mesh(*args.mesh_shape)
     health = (ExpertHealth(len(lib))
               if args.fallback_depth > 0 or args.fail_expert else None)
     eng = TryageEngine(lib, rp, rc,
@@ -317,9 +322,10 @@ def main():
                        max_wait_s=args.max_wait_s,
                        decision_cache=not args.no_cache,
                        cache_dir=(args.cache_dir
-                                  if "persistent" in tiers else None),
+                                  if "persistent" in args.tiers else None),
                        cache_semantic_eps=(args.cache_semantic
-                                           if "semantic" in tiers else 0.0),
+                                           if "semantic" in args.tiers
+                                           else 0.0),
                        cascade_max_depth=args.cascade_depth,
                        fused_cascade=args.fused_cascade,
                        speculate=args.speculate,
@@ -328,7 +334,7 @@ def main():
                        replay_cap=args.replay_cap,
                        health=health,
                        fallback_max_depth=args.fallback_depth,
-                       mesh=mesh,
+                       mesh=mesh, placement=placement,
                        replicate_hot=args.replicate_hot)
     if mesh is not None:
         # pre-compile every (expert, replica device, bucket) variant so
@@ -368,16 +374,12 @@ def main():
                     min_confidence=args.cascade)
             for i in range(args.requests)]
 
-    names = [e.name for e in lib]
+    names = [e.name for e in eng.library.experts]
     fail_idx = None
     if args.fail_expert:
         if args.fail_expert not in names:
             raise SystemExit(f"--fail-expert must be one of {names}")
-        if args.fifo:
-            ap.error("--fail-expert needs the scheduler (drop --fifo)")
         fail_idx = names.index(args.fail_expert)
-    if args.sessions > 0 and args.fifo:
-        ap.error("--sessions needs the streaming engine (drop --fifo)")
 
     # arm the failure injection mid-stream: once --fail-after requests
     # have been admitted, every flush of the target expert's lanes fails
@@ -428,7 +430,7 @@ def main():
         eng.cache.close()
     accs = [r.accuracy for r in results if r.accuracy is not None]
     losses = [r.loss for r in results if r.loss is not None]
-    print(json.dumps({
+    summary = {
         "requests": len(results),
         "router_path": "fused-kernel" if args.use_kernel else "host",
         "discipline": "fifo-drain" if args.fifo else "continuous-batching",
@@ -442,14 +444,50 @@ def main():
         "sessions": args.sessions,
         "fallback_depth": args.fallback_depth,
         "fail_expert": args.fail_expert or None,
-        "cache_tiers": sorted(tiers) if not args.no_cache else [],
+        "cache_tiers": sorted(args.tiers) if not args.no_cache else [],
         "mesh": eng.mesh_summary(),
         "wall_s": round(dt, 2),
         "req_per_s": round(len(results) / dt, 1),
         "mean_mlm_accuracy": round(float(np.mean(accs)), 4),
         "mean_mlm_loss": round(float(np.mean(losses)), 4),
         "engine": eng.stats.summary(),
-    }, indent=1))
+    }
+    return ServeRun(eng, reqs, results, summary)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
+    if args.tile_table:
+        from repro.kernels import tiles
+        tiles.set_table_path(args.tile_table)
+
+    if args.sanitize:
+        from repro.kernels import sanitize
+        sanitize.set_sanitize(True)
+
+    from repro.core import experiment as ex
+
+    try:
+        art = ex.load_artifacts()
+    except FileNotFoundError:
+        print("no artifacts; running reduced experiment first", flush=True)
+        xc = ex.ExperimentConfig(expert_steps=60, n_train_prompts=512,
+                                 n_val_prompts=128, n_test_per_domain=24,
+                                 router_epochs=3)
+        ex.run_experiment(xc, verbose=True)
+        art = ex.load_artifacts()
+
+    rp = art["router_params"]
+    if args.cascade > 0 and "unc" not in rp:
+        from repro.core.training import calibrate_uncertainty
+        print("calibrating uncertainty head on held-out Q-table", flush=True)
+        rp = calibrate_uncertainty(rp, art["rc"], art["test_tokens"],
+                                   art["q_test"]["loss"])
+    run = serve_library(art["library"], rp, art["rc"], art["corpus"], args)
+    print(json.dumps(run.summary, indent=1))
 
 
 if __name__ == "__main__":

@@ -184,7 +184,8 @@ class EngineStats:
     spec_wasted_tokens: int = 0
     # effective launch geometry of the fused decision kernel per padded
     # admission-batch size (the tile that actually ran after the
-    # block_b = min(block_b, B) clamp — summary/debug only)
+    # block_b = min(block_b, B) clamp) and its launch count, which says
+    # how often a batch spans more than one kernel tile
     router_tiles: dict = dataclasses.field(default_factory=dict)
     # online-adaptation telemetry: router updates applied (and the
     # resulting router version), feedback samples published, replay
@@ -584,7 +585,6 @@ class TryageEngine:
                     # decision runs under shard_map: per-device blocks
                     # of the batch through the same kernel, params
                     # replicated (P() spec)
-                    from jax.experimental.shard_map import shard_map
                     from jax.sharding import PartitionSpec as P
                     cmat = self._cmat
 
@@ -594,11 +594,11 @@ class TryageEngine:
                                                    lam,
                                                    interpret=interpret)
 
-                    self._decide_mesh = jax.jit(shard_map(
+                    self._decide_mesh = jax.jit(jax.shard_map(
                         _decide_sharded, mesh=mesh,
                         in_specs=(P(), P("data", None), P("data", None)),
                         out_specs=(P("data", None), P("data")),
-                        check_rep=False))
+                        check_vma=False))
                 else:
                     # GSPMD path: same predict_losses program, traced
                     # under the activation-sharding context so
@@ -626,9 +626,17 @@ class TryageEngine:
         if self.mesh is None:
             return None
         names = [e.name for e in self.library.experts]
+        # where the committed replicas actually live (filled by dispatch
+        # or warm_mesh), read back from the arrays themselves
+        replicas = defaultdict(set)
+        for (ei, _), ep in self._expert_params_on.items():
+            for leaf in jax.tree.leaves(ep):
+                replicas[names[ei]].update(d.id for d in leaf.devices())
         return {
             "mesh": {k: int(v) for k, v in self.mesh.shape.items()},
             "placement": self.placement.summary(names),
+            "replica_devices": {n: sorted(replicas[n]) for n in names
+                                if n in replicas},
             "streams": self.streams.summary(),
         }
 
@@ -759,7 +767,9 @@ class TryageEngine:
                 # effective tile actually launched for this padded batch
                 # (block_b silently clamps to the batch — see
                 # kernels.router_score.kernel.launch_plan)
-                self.stats.router_tiles[Bp] = rs_ops.decision_plan(Bp)
+                self.stats.router_tiles[Bp] = {
+                    **rs_ops.decision_plan(Bp), "launches": 0}
+            self.stats.router_tiles[Bp]["launches"] += 1
             if sanitize.sanitize_enabled():
                 self._sanitize_batch(toks, pred, choice)
             pred = np.asarray(pred)[:B]
@@ -834,7 +844,9 @@ class TryageEngine:
         pred, sigma, choice, esc = self._decide_cascade(
             self.router_params, jnp.asarray(toks), jnp.asarray(lam))
         if Bp not in self.stats.router_tiles:
-            self.stats.router_tiles[Bp] = rc_ops.decision_plan(Bp)
+            self.stats.router_tiles[Bp] = {**rc_ops.decision_plan(Bp),
+                                           "launches": 0}
+        self.stats.router_tiles[Bp]["launches"] += 1
         if sanitize.sanitize_enabled():
             self._sanitize_batch(toks, pred, choice)
         pred = np.asarray(pred)[:B]

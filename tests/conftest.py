@@ -1,5 +1,9 @@
-"""Shared fixtures. NOTE: tests must see the single real CPU device —
-the 512-device XLA flag belongs ONLY to launch/dryrun.py subprocesses."""
+"""Shared fixtures.  The tests run on the CPU backend
+(``JAX_PLATFORMS=cpu``), where the Pallas kernels run in interpret mode;
+``test_tpu_compile.py`` compiles for a described TPU without one.  Tests
+see one CPU device unless ``XLA_FLAGS=--xla_force_host_platform_device_
+count=N`` is set for the multi-device tests; the 512-device flag belongs
+only to ``launch/dryrun.py`` subprocesses."""
 
 import jax
 import numpy as np

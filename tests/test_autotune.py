@@ -17,7 +17,8 @@ import pytest
 from repro.kernels import tiles
 from repro.kernels.router_score.kernel import launch_plan
 from repro.launch import autotune as at
-from repro.launch.roofline import PRESETS, Roofline, resolve_preset
+from repro.launch.roofline import (PRESETS, Roofline, preset_for,
+                                   resolve_preset)
 
 
 # ------------------------------------------------------------ roofline
@@ -30,6 +31,23 @@ def test_presets_and_resolution():
     assert resolve_preset(None) in PRESETS.values()
     with pytest.raises(KeyError):
         resolve_preset("h100-from-the-future")
+
+
+@pytest.mark.parametrize("backend,kind,want", [
+    ("tpu", "TPU v5 lite", "tpu-v5e"),
+    ("tpu", "TPU v4", None),
+    ("cuda", "NVIDIA A100", "gpu"),
+    ("cpu", "cpu", "cpu"),
+    ("metal", "Apple M2", None),
+])
+def test_preset_for_keys_tpus_by_device_kind(backend, kind, want):
+    """A TPU's peaks come from its device kind; a kind or backend with
+    no preset raises instead of borrowing another chip's peaks."""
+    if want is None:
+        with pytest.raises(ValueError):
+            preset_for(backend, kind)
+    else:
+        assert preset_for(backend, kind) is PRESETS[want]
 
 
 def test_roofline_uses_preset_ceilings():

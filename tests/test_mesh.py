@@ -290,8 +290,10 @@ def test_2x4_mesh_matches_single_device_choices_and_nll(tiny_library,
     on every measured per-request NLL to float tolerance."""
     # mixed cascade thresholds: every 4th request escalates (constant
     # uncertainty prior -> conf 0.5 < 0.99), the rest keep the router's
-    # first pick so traffic spreads over the library
-    reqs = [dataclasses.replace(r, min_confidence=0.99 if i % 4 == 0
+    # first pick so traffic spreads over the library.  The escalating
+    # rows carry the heavy size penalty, so their first pick sits below
+    # the top rung and has somewhere to escalate to.
+    reqs = [dataclasses.replace(r, min_confidence=0.99 if i % 4 == 2
                                 else 0.0)
             for i, r in enumerate(_requests(128, seed=11, n_unique=96))]
     outs, engines = [], []
