@@ -214,13 +214,17 @@ def forward(params, cfg: ModelConfig, batch, *, mode: str, state=None,
     x = apply_norm(params["final_norm"], x, cfg.norm_eps, cfg.norm_kind)
     if mode == "encode":
         return x, new_states, aux
+    return lm_logits(params, cfg, x), new_states, aux
+
+
+def lm_logits(params, cfg: ModelConfig, x):
+    """Vocabulary logits (B, S, V) from final-norm hidden states."""
     if cfg.tie_embeddings:
         logits = apply_unembed(params["embed"], x)
     else:
         from repro.models.layers import apply_dense
         logits = apply_dense(params["head"], x)
-    logits = shard_act(logits, ("batch", "seq", "vocab"))
-    return logits, new_states, aux
+    return shard_act(logits, ("batch", "seq", "vocab"))
 
 
 def encode(params, cfg: ModelConfig, batch, remat=False, attn_impl="xla"):

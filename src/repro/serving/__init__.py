@@ -7,6 +7,7 @@ from repro.serving.kvstore import (DiskKVStore, KVStore, MemoryKVStore,
                                    SimulatedCrash)
 from repro.serving.metrics import (MetricSpec, MetricsServer, metric_names,
                                    render, start_metrics_server)
+from repro.serving import tracing
 from repro.serving.pipeline import (CascadeStage, ExecuteStage,
                                     FallbackStage, FeedbackStage,
                                     FlushContext, RouteContext, RouteStage,
@@ -28,4 +29,4 @@ __all__ = ["TryageEngine", "EngineStats", "Request", "Result",
            "ExpertHealth", "ExpertState",
            "ServingFrontend", "Session", "AdmissionQueue",
            "MetricSpec", "MetricsServer", "metric_names", "render",
-           "start_metrics_server"]
+           "start_metrics_server", "tracing"]

@@ -81,8 +81,8 @@ in ``EngineStats``.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
+import re
 import time
 from collections import defaultdict, deque
 from typing import Callable, Iterable, Iterator, Sequence
@@ -103,7 +103,7 @@ from repro.core.training import (make_router_update_step,
 from repro.kernels import sanitize
 from repro.kernels.router_cascade import ops as rc_ops
 from repro.kernels.router_score import ops as rs_ops
-from repro.models.model import forward
+from repro.models.model import forward, lm_logits
 from repro.serving.cache import DecisionCache, DecisionCacheStack
 from repro.serving.semcache import SemanticCache
 from repro.serving.feedback import ReplayBuffer
@@ -112,6 +112,7 @@ from repro.serving.pipeline import RouteContext, ServingPipeline
 from repro.serving.placement import (PlacementMap, StreamClock,
                                      plan_placement)
 from repro.serving.requests import Request, Result, lambda_matrix
+from repro.serving import tracing
 from repro.serving.scheduler import ExpertScheduler, LaneEntry
 from repro.sharding.context import (activation_sharding, batch_sharding,
                                     replicated_sharding)
@@ -145,6 +146,13 @@ class EngineStats:
     # bounded window so a long-running serve() keeps O(1) memory;
     # percentiles are over the most recent 64k requests
     latencies: deque = dataclasses.field(
+        default_factory=lambda: deque(maxlen=65536))
+    # per-row waits over the same window: admission start minus
+    # Request.arrival (queue), and flush start minus LaneEntry.pushed
+    # (lane)
+    queue_waits: deque = dataclasses.field(
+        default_factory=lambda: deque(maxlen=65536))
+    lane_waits: deque = dataclasses.field(
         default_factory=lambda: deque(maxlen=65536))
     # router-decision cache telemetry.  Tier attribution: "t1" is the
     # in-process exact LRU, "t2" the persistent KV store, "t3" the
@@ -492,46 +500,57 @@ class TryageEngine:
 
         # lazy sigma pass: only cascade-enabled requests pay for it, so
         # the min_confidence=0 path runs the exact pre-cascade jits
-        self._sigma = jax.jit(
-            lambda p, toks: predict_uncertainty(p, rc, {"tokens": toks}))
+        def tryage_sigma(p, toks):
+            return predict_uncertainty(p, rc, {"tokens": toks})
+
+        self._sigma = jax.jit(tryage_sigma)
 
         # semantic-tier path: pooled embedding and head-from-embedding
         # jits, compiled only if the semantic cache tier is enabled (the
         # T3 probe needs the embedding before it knows whether a fresh
         # score is needed, so the score is split at the embedding)
-        self._embed = jax.jit(
-            lambda p, toks: router_embed(p, rc, {"tokens": toks}))
-        self._head_from_emb = jax.jit(
-            lambda p, emb: losses_from_emb(p["head"], emb))
+        def tryage_embed(p, toks):
+            return router_embed(p, rc, {"tokens": toks})
+
+        def tryage_head_from_emb(p, emb):
+            return losses_from_emb(p["head"], emb)
+
+        self._embed = jax.jit(tryage_embed)
+        self._head_from_emb = jax.jit(tryage_head_from_emb)
 
         if use_kernel:
             cmat = self._cmat
 
-            def _decide(p, toks, lam):
-                emb = router_embed(p, rc, {"tokens": toks})
-                return rs_ops.router_route(emb, p["head"], cmat, lam,
-                                           interpret=interpret)
+            def tryage_decide(p, toks, lam):
+                with jax.named_scope("router_encoder"):
+                    emb = router_embed(p, rc, {"tokens": toks})
+                with jax.named_scope("decision_head"):
+                    return rs_ops.router_route(emb, p["head"], cmat, lam,
+                                               interpret=interpret)
 
-            self._decide = jax.jit(_decide)
+            self._decide = jax.jit(tryage_decide)
             if fused_cascade:
                 ladder = jnp.asarray(self._ladder_pos, jnp.int32)
 
-                def _decide_cascade(p, toks, lam):
-                    emb = router_embed(p, rc, {"tokens": toks})
-                    return rc_ops.router_route_cascade(
-                        emb, p["head"], p["unc"], cmat, lam, ladder,
-                        interpret=interpret)
+                def tryage_decide_cascade(p, toks, lam):
+                    with jax.named_scope("router_encoder"):
+                        emb = router_embed(p, rc, {"tokens": toks})
+                    with jax.named_scope("decision_head"):
+                        return rc_ops.router_route_cascade(
+                            emb, p["head"], p["unc"], cmat, lam, ladder,
+                            interpret=interpret)
 
-                self._decide_cascade = jax.jit(_decide_cascade)
+                self._decide_cascade = jax.jit(tryage_decide_cascade)
         else:
-            self._score = jax.jit(
-                lambda p, toks: predict_losses(p, rc, {"tokens": toks},
-                                               use_kernel=False))
+            def tryage_score(p, toks):
+                return predict_losses(p, rc, {"tokens": toks},
+                                      use_kernel=False)
+
+            self._score = jax.jit(tryage_score)
         self._expert_fns = {}
         self._expert_idx = {}
         for i, e in enumerate(library.experts):
-            self._expert_fns[e.name] = jax.jit(
-                functools.partial(self._expert_forward, cfg=e.cfg))
+            self._expert_fns[e.name] = self._expert_program(e)
             self._expert_idx[e.name] = i
 
         # ------------------------------------------------ mesh wiring
@@ -697,19 +716,36 @@ class TryageEngine:
         Padded rows carry an all-zero mask, so their loss/accuracy reduce
         to 0 under the max(denominator, 1) guard and are dropped host-side.
         """
-        logits, _, _ = forward(params, cfg, {"tokens": toks}, mode="train",
-                               remat=False)
-        logits = logits.astype(jnp.float32)
-        preds = jnp.argmax(logits, axis=-1)
-        # masked token NLL, one-hot contraction (see models.model.cross_entropy)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        onehot = jax.nn.one_hot(targets, logits.shape[-1], dtype=jnp.float32)
-        gold = jnp.einsum("bsv,bsv->bs", logits, onehot)
-        m = mask.astype(jnp.float32)
-        denom = jnp.maximum(m.sum(-1), 1.0)
-        ex_loss = ((logz - gold) * m).sum(-1) / denom
-        ex_acc = ((preds == targets) * m).sum(-1) / denom
+        with jax.named_scope("expert_encoder"):
+            hidden, _, _ = forward(params, cfg, {"tokens": toks},
+                                   mode="encode", remat=False)
+        with jax.named_scope("mlm_logits_nll"):
+            logits = lm_logits(params, cfg, hidden).astype(jnp.float32)
+            preds = jnp.argmax(logits, axis=-1)
+            # masked token NLL, one-hot contraction (see
+            # models.model.cross_entropy)
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            onehot = jax.nn.one_hot(targets, logits.shape[-1],
+                                    dtype=jnp.float32)
+            gold = jnp.einsum("bsv,bsv->bs", logits, onehot)
+            m = mask.astype(jnp.float32)
+            denom = jnp.maximum(m.sum(-1), 1.0)
+            ex_loss = ((logz - gold) * m).sum(-1) / denom
+            ex_acc = ((preds == targets) * m).sum(-1) / denom
         return preds, ex_loss, ex_acc
+
+    def _expert_program(self, e):
+        """The jitted step of expert ``e``, named so that its device
+        program reads ``jit_tryage_expert_<name>`` in a trace."""
+        cfg = e.cfg
+
+        def program(params, toks, targets, mask):
+            return self._expert_forward(params, toks, targets, mask,
+                                        cfg=cfg)
+
+        program.__name__ = program.__qualname__ = (
+            "tryage_expert_" + re.sub(r"\W", "_", e.name))
+        return jax.jit(program)
 
     # ------------------------------------------------------------- api
 
@@ -732,37 +768,40 @@ class TryageEngine:
         int under each request's lambda-weighted constraints.
         """
         B = len(reqs)
-        toks = np.stack([r.tokens for r in reqs])
         t0 = self._now()
         data_par = self._data_ext > 1
         if self.use_kernel:
             # fused path: constraint add + argmin happen on-device inside
             # router_score_fused; pad to a bucket so the jit'd decision
             # function compiles once per bucket, not per ragged tail.
-            lam = lambda_matrix(reqs, self._cnames)
-            Bp = self._bucket(B)
-            if data_par and Bp % self._data_ext:
-                # shard_map needs the batch divisible by the data axis
-                Bp += self._data_ext - Bp % self._data_ext
-            if Bp != B:
-                toks = np.concatenate(
-                    [toks, np.zeros((Bp - B,) + toks.shape[1:], toks.dtype)])
-                lam = np.concatenate(
-                    [lam, np.zeros((Bp - B, lam.shape[1]), lam.dtype)])
-            if data_par:
-                # data-parallel decision: batch rows sharded over the
-                # mesh's "data" axis, params replicated, the same fused
-                # kernel per device block (shard_map — see __init__)
-                bs = batch_sharding(self.mesh, 2, toks.shape)
-                pred, choice = self._decide_mesh(
-                    self._mesh_router_params(),
-                    jax.device_put(toks, bs),
-                    jax.device_put(lam, batch_sharding(self.mesh, 2,
-                                                       lam.shape)))
-            else:
-                pred, choice = self._decide(self.router_params,
-                                            jnp.asarray(toks),
-                                            jnp.asarray(lam))
+            with tracing.span("admit.dispatch"):
+                toks = np.stack([r.tokens for r in reqs])
+                lam = lambda_matrix(reqs, self._cnames)
+                Bp = self._bucket(B)
+                if data_par and Bp % self._data_ext:
+                    # shard_map needs the batch divisible by the data axis
+                    Bp += self._data_ext - Bp % self._data_ext
+                if Bp != B:
+                    toks = np.concatenate(
+                        [toks,
+                         np.zeros((Bp - B,) + toks.shape[1:], toks.dtype)])
+                    lam = np.concatenate(
+                        [lam, np.zeros((Bp - B, lam.shape[1]), lam.dtype)])
+                if data_par:
+                    # data-parallel decision: batch rows sharded over the
+                    # mesh's "data" axis, params replicated, the same
+                    # fused kernel per device block (shard_map — see
+                    # __init__)
+                    bs = batch_sharding(self.mesh, 2, toks.shape)
+                    pred, choice = self._decide_mesh(
+                        self._mesh_router_params(),
+                        jax.device_put(toks, bs),
+                        jax.device_put(lam, batch_sharding(self.mesh, 2,
+                                                           lam.shape)))
+                else:
+                    pred, choice = self._decide(self.router_params,
+                                                jnp.asarray(toks),
+                                                jnp.asarray(lam))
             if Bp not in self.stats.router_tiles:
                 # effective tile actually launched for this padded batch
                 # (block_b silently clamps to the batch — see
@@ -772,35 +811,36 @@ class TryageEngine:
             self.stats.router_tiles[Bp]["launches"] += 1
             if sanitize.sanitize_enabled():
                 self._sanitize_batch(toks, pred, choice)
-            pred = np.asarray(pred)[:B]
-            choice = np.asarray(choice)[:B]
+            with tracing.span("admit.device"):
+                pred = np.asarray(pred)[:B]
+                choice = np.asarray(choice)[:B]
         else:
-            if data_par:
-                Bp = B
-                if Bp % self._data_ext:
-                    Bp += self._data_ext - Bp % self._data_ext
-                    toks = np.concatenate(
-                        [toks,
-                         np.zeros((Bp - B,) + toks.shape[1:], toks.dtype)])
-                # GSPMD data-parallel scoring: inputs NamedSharding'd by
-                # batch (sharding/rules.py "batch" -> "data"), traced
-                # under the activation-sharding context so the encoder
-                # keeps the batch axis sharded end to end
-                tsh = jax.device_put(toks,
-                                     batch_sharding(self.mesh, 2,
-                                                    toks.shape))
-                with activation_sharding(self.mesh, DEFAULT_RULES):
-                    pred_dev = self._score_mesh(self._mesh_router_params(),
-                                                tsh)
-                if sanitize.sanitize_enabled():
-                    self._sanitize_batch(toks, pred_dev)
+            with tracing.span("admit.dispatch"):
+                toks = np.stack([r.tokens for r in reqs])
+                if data_par:
+                    Bp = B
+                    if Bp % self._data_ext:
+                        Bp += self._data_ext - Bp % self._data_ext
+                        toks = np.concatenate(
+                            [toks, np.zeros((Bp - B,) + toks.shape[1:],
+                                            toks.dtype)])
+                    # GSPMD data-parallel scoring: inputs NamedSharding'd
+                    # by batch (sharding/rules.py "batch" -> "data"),
+                    # traced under the activation-sharding context so the
+                    # encoder keeps the batch axis sharded end to end
+                    tsh = jax.device_put(toks,
+                                         batch_sharding(self.mesh, 2,
+                                                        toks.shape))
+                    with activation_sharding(self.mesh, DEFAULT_RULES):
+                        pred_dev = self._score_mesh(
+                            self._mesh_router_params(), tsh)
+                else:
+                    pred_dev = self._score(self.router_params,
+                                           jnp.asarray(toks))
+            if sanitize.sanitize_enabled():
+                self._sanitize_batch(toks, pred_dev)
+            with tracing.span("admit.device"):
                 pred = np.asarray(pred_dev)[:B]
-            else:
-                pred_dev = self._score(self.router_params,
-                                       jnp.asarray(toks))
-                if sanitize.sanitize_enabled():
-                    self._sanitize_batch(toks, pred_dev)
-                pred = np.asarray(pred_dev)
             # score = L-hat + sum_j lambda_j C_j, argmin on the host
             scores = pred.copy()
             for c in self.constraints:
@@ -832,27 +872,29 @@ class TryageEngine:
         (``kernels.router_cascade``).  Mirrors ``_score_batch``'s
         bucket padding and telemetry."""
         B = len(reqs)
-        toks = np.stack([r.tokens for r in reqs])
-        lam = lambda_matrix(reqs, self._cnames)
         t0 = self._now()
-        Bp = self._bucket(B)
-        if Bp != B:
-            toks = np.concatenate(
-                [toks, np.zeros((Bp - B,) + toks.shape[1:], toks.dtype)])
-            lam = np.concatenate(
-                [lam, np.zeros((Bp - B, lam.shape[1]), lam.dtype)])
-        pred, sigma, choice, esc = self._decide_cascade(
-            self.router_params, jnp.asarray(toks), jnp.asarray(lam))
+        with tracing.span("admit.dispatch"):
+            toks = np.stack([r.tokens for r in reqs])
+            lam = lambda_matrix(reqs, self._cnames)
+            Bp = self._bucket(B)
+            if Bp != B:
+                toks = np.concatenate(
+                    [toks, np.zeros((Bp - B,) + toks.shape[1:], toks.dtype)])
+                lam = np.concatenate(
+                    [lam, np.zeros((Bp - B, lam.shape[1]), lam.dtype)])
+            pred, sigma, choice, esc = self._decide_cascade(
+                self.router_params, jnp.asarray(toks), jnp.asarray(lam))
         if Bp not in self.stats.router_tiles:
             self.stats.router_tiles[Bp] = {**rc_ops.decision_plan(Bp),
                                            "launches": 0}
         self.stats.router_tiles[Bp]["launches"] += 1
         if sanitize.sanitize_enabled():
             self._sanitize_batch(toks, pred, choice)
-        pred = np.asarray(pred)[:B]
-        sigma = np.asarray(sigma)[:B]
-        choice = np.asarray(choice)[:B]
-        esc = np.asarray(esc)[:B]
+        with tracing.span("admit.device"):
+            pred = np.asarray(pred)[:B]
+            sigma = np.asarray(sigma)[:B]
+            choice = np.asarray(choice)[:B]
+            esc = np.asarray(esc)[:B]
         self.stats.router_time_s += self._now() - t0
         self.stats.router_batches += 1
         return pred, choice, sigma, esc
@@ -1112,15 +1154,17 @@ class TryageEngine:
         n = len(reqs)
         Bp = self._bucket(n)
         S = len(reqs[0].tokens)
-        toks = np.zeros((Bp, S), reqs[0].tokens.dtype)
-        targets = np.zeros((Bp, S), np.int32)
-        mask = np.zeros((Bp, S), np.int32)
-        for j, r in enumerate(reqs):
-            toks[j] = r.tokens
-            if r.targets is not None:
-                targets[j] = r.targets
-            if r.mask is not None:
-                mask[j] = r.mask
+        with tracing.span("flush.pad"):
+            toks = np.zeros((Bp, S), reqs[0].tokens.dtype)
+            targets = np.zeros((Bp, S), np.int32)
+            mask = np.zeros((Bp, S), np.int32)
+            for j, r in enumerate(reqs):
+                toks[j] = r.tokens
+                if r.targets is not None:
+                    targets[j] = r.targets
+                if r.mask is not None:
+                    mask[j] = r.mask
+        fn = self._expert_fns[e.name]
         if self.placement is not None:
             ei = self._expert_idx[e.name]
             slot = self.streams.least_busy(self._expert_streams[ei])
@@ -1131,24 +1175,25 @@ class TryageEngine:
                 ep = jax.device_put(e.params, dev)
                 self._expert_params_on[key] = ep
             t0 = self._now()
-            preds, ex_loss, ex_acc = self._expert_fns[e.name](
-                ep, jax.device_put(toks, dev),
-                jax.device_put(targets, dev), jax.device_put(mask, dev))
-            out = (np.asarray(preds)[:n], np.asarray(ex_loss)[:n],
-                   np.asarray(ex_acc)[:n])
+            with tracing.span("flush.dispatch"):
+                outs = fn(ep, jax.device_put(toks, dev),
+                          jax.device_put(targets, dev),
+                          jax.device_put(mask, dev))
+        else:
+            with tracing.span("flush.dispatch"):
+                outs = fn(e.params, jnp.asarray(toks), jnp.asarray(targets),
+                          jnp.asarray(mask))
+        with tracing.span("flush.device"):
+            jax.block_until_ready(outs)
+        with tracing.span("flush.fetch"):
+            out = tuple(np.asarray(o)[:n] for o in outs)
+        if self.placement is not None:
             # attribute the flush's (blocked) wall time to its stream —
             # the overlapped-makespan signal bench_mesh scales on
             self.streams.record(slot, self._now() - t0, tokens=n * S)
-            self.stats.bucket_hits[Bp] += 1
-            self.stats.padded_rows += Bp - n
-            return out
-        preds, ex_loss, ex_acc = self._expert_fns[e.name](
-            e.params, jnp.asarray(toks), jnp.asarray(targets),
-            jnp.asarray(mask))
         self.stats.bucket_hits[Bp] += 1
         self.stats.padded_rows += Bp - n
-        return (np.asarray(preds)[:n], np.asarray(ex_loss)[:n],
-                np.asarray(ex_acc)[:n])
+        return out
 
     def _execute(self, expert_idx: int, entries: list[LaneEntry],
                  reason: str) -> list[Result]:
@@ -1271,7 +1316,8 @@ class TryageEngine:
             if degraded:
                 self.stats.degraded += 1
             sched.push(final, en.req, en.pred, en.cached, en.depth,
-                       en.confidence, en.fallback_depth + fdepth)
+                       en.confidence, en.fallback_depth + fdepth,
+                       pushed=now)
         return failed
 
     # -------------------------------------------------------- disciplines
@@ -1289,13 +1335,14 @@ class TryageEngine:
                                  self.queue[self.max_batch:])
             (pred, choice, cached, depth, conf,
              fdepth) = self._route_admitted(batch)
+            pushed = self._now()
             by_expert: dict[int, list[int]] = defaultdict(list)
             for i, c in enumerate(choice):
                 by_expert[int(c)].append(i)
             for mi, idxs in sorted(by_expert.items()):
                 entries = [LaneEntry(batch[i], pred[i], i, bool(cached[i]),
                                      int(depth[i]), float(conf[i]),
-                                     int(fdepth[i]))
+                                     int(fdepth[i]), pushed=pushed)
                            for i in idxs]
                 results.extend(self._execute(mi, entries, "fifo"))
         return results
@@ -1353,11 +1400,14 @@ class TryageEngine:
         held: dict = {}       # uid -> Result awaiting its verdict
 
         def _push_ctx(ctx, specs=frozenset()):
-            for i, r in enumerate(ctx.reqs):
-                sched.push(int(ctx.choice[i]), r, ctx.pred[i],
-                           bool(ctx.cached[i]), int(ctx.depth[i]),
-                           float(ctx.confidence[i]),
-                           int(ctx.fallback_depth[i]), spec=i in specs)
+            t = self._now()
+            with tracing.span("lanes.push", start=t, rows=len(ctx.reqs)):
+                for i, r in enumerate(ctx.reqs):
+                    sched.push(int(ctx.choice[i]), r, ctx.pred[i],
+                               bool(ctx.cached[i]), int(ctx.depth[i]),
+                               float(ctx.confidence[i]),
+                               int(ctx.fallback_depth[i]), spec=i in specs,
+                               pushed=t)
 
         def _admit():
             reqs = list(admitted)
@@ -1366,7 +1416,8 @@ class TryageEngine:
                 # lane everything on the router's first pick now; the
                 # sigma/escalation verdict lands via _resolve() after
                 # this tick's flushes have launched
-                ctx = self.pipeline.route(RouteContext(reqs))
+                with self.pipeline.admission(reqs) as ctx:
+                    self.pipeline.route(ctx)
                 spec_rows = [i for i in ctx.miss_idx
                              if reqs[i].min_confidence > 0.0]
                 if spec_rows:
@@ -1382,12 +1433,7 @@ class TryageEngine:
                     self.pipeline.fallback(self.pipeline.cascade(ctx))
                     _push_ctx(ctx)
             else:
-                (pred, choice, cached, depth, conf,
-                 fdepth) = self._route_admitted(reqs)
-                for i, r in enumerate(reqs):
-                    sched.push(int(choice[i]), r, pred[i],
-                               bool(cached[i]), int(depth[i]),
-                               float(conf[i]), int(fdepth[i]))
+                _push_ctx(self.pipeline.admit(reqs))
             if self.health is not None:
                 # saturation signal: every expert's pending depth folds
                 # into its health EWMA at each admission (zeros included
@@ -1427,7 +1473,7 @@ class TryageEngine:
                         # escalation target — no wasted compute
                         self.stats.spec_cancelled += 1
                         sched.push(final, r, en.pred, en.cached, d, cf,
-                                   en.fallback_depth)
+                                   en.fallback_depth, pushed=self._now())
                     else:
                         # the provisional copy already executed: count
                         # the waste, revert its per-request accounting,
@@ -1437,7 +1483,8 @@ class TryageEngine:
                         self._unrecord_result(held.pop(r.uid))
                         sched.push(final, r, ctx.pred[i],
                                    bool(ctx.cached[i]), d, cf,
-                                   int(ctx.fallback_depth[i]))
+                                   int(ctx.fallback_depth[i]),
+                                   pushed=self._now())
 
         if self.queue:
             queued, self.queue = self.queue, []

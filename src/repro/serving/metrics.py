@@ -184,6 +184,14 @@ METRICS: tuple[MetricSpec, ...] = (
                "True enqueue-to-flush latency over the most recent "
                "latency window.",
                "EngineStats.latencies"),
+    MetricSpec("tryage_queue_wait_seconds", "histogram", (),
+               "Admission start minus Request.arrival per admitted row, "
+               "over the most recent window.",
+               "EngineStats.queue_waits"),
+    MetricSpec("tryage_lane_wait_seconds", "histogram", (),
+               "Flush start minus the time the row was laned, per "
+               "flushed row, over the most recent window.",
+               "EngineStats.lane_waits"),
     # ------------------------------------------------- expert health
     MetricSpec("tryage_expert_healthy", "gauge", ("expert",),
                "1 if the expert passes the health checks (no forced "
@@ -324,6 +332,8 @@ def render(stats, health=None, expert_names: Sequence[str] | None = None
     _scalar(w, "tryage_sessions", stats.sessions)
     _scalar(w, "tryage_admission_queue_peak", stats.admission_queue_peak)
     _histogram(w, "tryage_request_latency_seconds", stats.latencies)
+    _histogram(w, "tryage_queue_wait_seconds", stats.queue_waits)
+    _histogram(w, "tryage_lane_wait_seconds", stats.lane_waits)
     health_series = (
         ("tryage_expert_healthy",
          lambda i: 1.0 if health.healthy(i) else 0.0),

@@ -36,11 +36,14 @@ implementation of the old hard-wired flow).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import itertools
 from typing import TYPE_CHECKING
 
 import numpy as np
 
+from repro.serving import tracing
 from repro.serving.cache import DecisionCache
 from repro.serving.requests import Request, Result
 from repro.serving.scheduler import LaneEntry
@@ -80,15 +83,18 @@ class RouteContext:
     # aligned with it.  None = staged scoring, Cascade runs the
     # sigma pass itself.
     fused: tuple | None = None
+    admit_id: int = -1                      # links the batch's spans
 
 
 @dataclasses.dataclass
 class FlushContext:
-    """One per-expert micro-batch flowing Execute -> Feedback."""
+    """One per-expert micro-batch flowing Execute -> Feedback;
+    ``start`` is the flush's start on the engine clock."""
 
     expert_idx: int
     entries: list[LaneEntry]
     reason: str
+    start: float
     results: list[Result] = dataclasses.field(default_factory=list)
 
 
@@ -119,20 +125,22 @@ class RouteStage:
             ctx.miss_idx = list(range(B))
             return ctx
         sink = self._dropped_lambda_sink
-        ctx.keys = [DecisionCache.key(r.tokens, r.lambdas, eng._cnames,
-                                      r.min_confidence, eng.router_version,
-                                      unknown_sink=sink)
-                    for r in ctx.reqs]
         misses = []
-        for i, key in enumerate(ctx.keys):
-            hit, tier = eng.cache.lookup(key)
-            if hit is None:
-                misses.append(i)
-            else:
-                (ctx.pred[i], ctx.choice[i], ctx.depth[i],
-                 ctx.confidence[i]) = hit
-                ctx.cached[i] = True
-                eng.stats.cache_tier_hits[tier] += 1
+        with tracing.span("admit.cache"):
+            ctx.keys = [DecisionCache.key(r.tokens, r.lambdas, eng._cnames,
+                                          r.min_confidence,
+                                          eng.router_version,
+                                          unknown_sink=sink)
+                        for r in ctx.reqs]
+            for i, key in enumerate(ctx.keys):
+                hit, tier = eng.cache.lookup(key)
+                if hit is None:
+                    misses.append(i)
+                else:
+                    (ctx.pred[i], ctx.choice[i], ctx.depth[i],
+                     ctx.confidence[i]) = hit
+                    ctx.cached[i] = True
+                    eng.stats.cache_tier_hits[tier] += 1
         if misses and getattr(eng.cache, "semantic", None) is not None:
             misses = self._semantic_probe(ctx, misses)
         if misses:
@@ -216,9 +224,13 @@ class CascadeStage:
         self.eng = engine
 
     def __call__(self, ctx: RouteContext) -> RouteContext:
-        eng = self.eng
         if not ctx.miss_idx:
             return ctx
+        with tracing.span("admit.cascade", admit_id=ctx.admit_id):
+            return self._cascade(ctx)
+
+    def _cascade(self, ctx: RouteContext) -> RouteContext:
+        eng = self.eng
         miss_reqs = [ctx.reqs[i] for i in ctx.miss_idx]
         mpred = ctx.pred[ctx.miss_idx]
         if ctx.fused is not None and ctx.fused[0] == ctx.miss_idx:
@@ -269,6 +281,11 @@ class FallbackStage:
         eng = self.eng
         if eng.health is None or eng.fallback_max_depth <= 0:
             return ctx
+        with tracing.span("admit.fallback", admit_id=ctx.admit_id):
+            return self._fallback(ctx)
+
+    def _fallback(self, ctx: RouteContext) -> RouteContext:
+        eng = self.eng
         avail = eng.health.available_mask()
         if avail.all():
             return ctx
@@ -311,37 +328,38 @@ class ExecuteStage:
     def __call__(self, ctx: FlushContext) -> FlushContext:
         eng = self.eng
         e = eng.library[ctx.expert_idx]
-        t0 = eng._now()
+        t0 = ctx.start
         preds, ex_loss, ex_acc = eng._run_expert(
             e, [en.req for en in ctx.entries])
         end = eng._now()
         eng.stats.expert_time_s += end - t0
         eng.stats.flushes[ctx.reason] += 1
-        for j, en in enumerate(ctx.entries):
-            r = en.req
-            loss = acc = None
-            if (r.targets is not None and r.mask is not None
-                    and r.mask.astype(bool).any()):
-                loss = float(ex_loss[j])
-                acc = float(ex_acc[j])
-            flops = 2.0 * e.n_params * len(r.tokens)
-            latency = (max(end - r.arrival, 0.0) if r.arrival is not None
-                       else end - t0)
-            ctx.results.append(Result(
-                uid=r.uid, expert=e.name, pred_losses=en.pred,
-                predictions=preds[j], loss=loss, accuracy=acc,
-                flops_proxy=flops, latency_s=latency, cached=en.cached,
-                flush_reason=ctx.reason, cascade_depth=en.depth,
-                confidence=en.confidence,
-                fallback_depth=en.fallback_depth))
-            eng.stats.served += 1
-            eng.stats.per_expert[e.name] += 1
-            eng.stats.total_flops += flops
-            eng.stats.latencies.append(latency)
-            eng.stats.cascade_depth_hist[en.depth] += 1
-            eng.stats.tier_latencies[en.depth].append(latency)
-            if en.depth > 0:
-                eng.stats.escalations += 1
+        with tracing.span("flush.results"):
+            for j, en in enumerate(ctx.entries):
+                r = en.req
+                loss = acc = None
+                if (r.targets is not None and r.mask is not None
+                        and r.mask.astype(bool).any()):
+                    loss = float(ex_loss[j])
+                    acc = float(ex_acc[j])
+                flops = 2.0 * e.n_params * len(r.tokens)
+                latency = (max(end - r.arrival, 0.0) if r.arrival is not None
+                           else end - t0)
+                ctx.results.append(Result(
+                    uid=r.uid, expert=e.name, pred_losses=en.pred,
+                    predictions=preds[j], loss=loss, accuracy=acc,
+                    flops_proxy=flops, latency_s=latency, cached=en.cached,
+                    flush_reason=ctx.reason, cascade_depth=en.depth,
+                    confidence=en.confidence,
+                    fallback_depth=en.fallback_depth))
+                eng.stats.served += 1
+                eng.stats.per_expert[e.name] += 1
+                eng.stats.total_flops += flops
+                eng.stats.latencies.append(latency)
+                eng.stats.cascade_depth_hist[en.depth] += 1
+                eng.stats.tier_latencies[en.depth].append(latency)
+                if en.depth > 0:
+                    eng.stats.escalations += 1
         return ctx
 
 
@@ -362,6 +380,10 @@ class FeedbackStage:
         self.eng = engine
 
     def __call__(self, ctx: FlushContext) -> FlushContext:
+        with tracing.span("flush.feedback"):
+            return self._feedback(ctx)
+
+    def _feedback(self, ctx: FlushContext) -> FlushContext:
         eng = self.eng
         if eng.replay is None:
             return ctx
@@ -388,19 +410,57 @@ class ServingPipeline:
                the PR-4 Route -> Cascade flow bit-for-bit.
     ``flush``  runs Execute -> Feedback on one per-expert micro-batch
                and returns its Results.
+
+    Both stamp their start on the engine clock: each admitted row's
+    queue wait (admission start - ``Request.arrival``) and each flushed
+    row's lane wait (flush start - ``LaneEntry.pushed``) land in
+    ``EngineStats``, and under a profiler session in the ``tryage.admit``
+    / ``tryage.flush`` spans (see ``serving.tracing``), with the uids.
     """
 
     def __init__(self, engine: "TryageEngine"):
+        self.eng = engine
         self.route = RouteStage(engine)
         self.cascade = CascadeStage(engine)
         self.fallback = FallbackStage(engine)
         self.execute = ExecuteStage(engine)
         self.feedback = FeedbackStage(engine)
+        self._admit_ids = itertools.count()
+        self._flush_ids = itertools.count()
+
+    @contextlib.contextmanager
+    def admission(self, reqs: list[Request]):
+        """The admission batch's context, inside its ``admit`` span;
+        records the rows' queue waits."""
+        eng = self.eng
+        t = eng._now()
+        ctx = RouteContext(reqs, admit_id=next(self._admit_ids))
+        n = len(reqs)
+        with tracing.span("admit", start=t, admit_id=ctx.admit_id, rows=n,
+                          bucket=eng._bucket(n)) as sp:
+            waits = [t - r.arrival if r.arrival is not None
+                     else float("nan") for r in reqs]
+            eng.stats.queue_waits.extend(w for w in waits if w == w)
+            if sp:
+                sp.set(uids=[r.uid for r in reqs], waits=waits)
+            yield ctx
 
     def admit(self, reqs: list[Request]) -> RouteContext:
-        return self.fallback(self.cascade(self.route(RouteContext(reqs))))
+        with self.admission(reqs) as ctx:
+            return self.fallback(self.cascade(self.route(ctx)))
 
     def flush(self, expert_idx: int, entries: list[LaneEntry],
               reason: str) -> list[Result]:
-        ctx = FlushContext(expert_idx, entries, reason)
-        return self.feedback(self.execute(ctx)).results
+        eng = self.eng
+        t = eng._now()
+        n = len(entries)
+        with tracing.span("flush", start=t, flush_id=next(self._flush_ids),
+                          expert=expert_idx, rows=n, bucket=eng._bucket(n),
+                          reason=reason) as sp:
+            waits = [t - en.pushed if en.pushed is not None
+                     else float("nan") for en in entries]
+            eng.stats.lane_waits.extend(w for w in waits if w == w)
+            if sp:
+                sp.set(uids=[en.req.uid for en in entries], waits=waits)
+            ctx = FlushContext(expert_idx, entries, reason, t)
+            return self.feedback(self.execute(ctx)).results

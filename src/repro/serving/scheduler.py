@@ -60,6 +60,8 @@ class LaneEntry:
     confidence: float = 1.0   # router confidence in the final expert
     fallback_depth: int = 0   # health-fallback re-selections so far
     spec: bool = False        # provisional: cascade verdict still pending
+    pushed: float | None = None  # laned at (engine clock); lane wait
+    #                              runs from here to the flush's start
 
     @property
     def sort_key(self) -> tuple:
@@ -180,15 +182,17 @@ class ExpertScheduler:
         confidence: float = 1.0,
         fallback_depth: int = 0,
         spec: bool = False,
+        pushed: float | None = None,
     ) -> None:
         """Enqueue a routed request; escalated requests (``depth > 0``)
         are re-enqueued into the target expert's escalation lane.
         ``spec`` marks the entry provisional — its cascade verdict is
-        still in flight and may cancel or confirm it."""
+        still in flight and may cancel or confirm it.  ``pushed`` stamps
+        when it was laned."""
         lanes = self.esc_lanes if depth > 0 else self.lanes
         lanes[expert_idx].push(
             LaneEntry(req, pred, self._seq, cached, depth, confidence,
-                      fallback_depth, spec)
+                      fallback_depth, spec, pushed)
         )
         self._seq += 1
 

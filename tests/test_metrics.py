@@ -54,6 +54,8 @@ class FakeStats:
         self.sessions = 2
         self.admission_queue_peak = 3
         self.latencies = [0.002, 0.004, 0.03, 0.2]
+        self.queue_waits = [0.001, 0.02]
+        self.lane_waits = [0.003, 0.04, 0.06]
         for k, v in kw.items():
             setattr(self, k, v)
 
