@@ -154,6 +154,9 @@ class EngineStats:
         default_factory=lambda: deque(maxlen=65536))
     lane_waits: deque = dataclasses.field(
         default_factory=lambda: deque(maxlen=65536))
+    # blocking host<->device waits made by flushes (one per flush: the
+    # outputs' fetch; the inputs' put and the step do not block)
+    flush_round_trips: int = 0
     # router-decision cache telemetry.  Tier attribution: "t1" is the
     # in-process exact LRU, "t2" the persistent KV store, "t3" the
     # semantic tier.  Revalidations count semantic candidates found
@@ -264,6 +267,7 @@ class EngineStats:
                                 sorted(self.bucket_hits.items())},
                 "padded_rows": self.padded_rows,
                 "flushes": dict(self.flushes),
+                "flush_round_trips": self.flush_round_trips,
                 "lane_peaks": dict(self.lane_peaks),
                 "latency": {k: round(v, 6) for k, v in
                             self.latency_percentiles().items()},
@@ -1155,7 +1159,7 @@ class TryageEngine:
         Bp = self._bucket(n)
         S = len(reqs[0].tokens)
         with tracing.span("flush.pad"):
-            toks = np.zeros((Bp, S), reqs[0].tokens.dtype)
+            toks = np.zeros((Bp, S), np.int32)
             targets = np.zeros((Bp, S), np.int32)
             mask = np.zeros((Bp, S), np.int32)
             for j, r in enumerate(reqs):
@@ -1165,28 +1169,30 @@ class TryageEngine:
                 if r.mask is not None:
                     mask[j] = r.mask
         fn = self._expert_fns[e.name]
+        params, dev = e.params, None
         if self.placement is not None:
             ei = self._expert_idx[e.name]
             slot = self.streams.least_busy(self._expert_streams[ei])
             dev = self._devices[slot]
             key = (ei, slot)
-            ep = self._expert_params_on.get(key)
-            if ep is None:
-                ep = jax.device_put(e.params, dev)
-                self._expert_params_on[key] = ep
+            params = self._expert_params_on.get(key)
+            if params is None:
+                params = jax.device_put(e.params, dev)
+                self._expert_params_on[key] = params
             t0 = self._now()
-            with tracing.span("flush.dispatch"):
-                outs = fn(ep, jax.device_put(toks, dev),
-                          jax.device_put(targets, dev),
-                          jax.device_put(mask, dev))
-        else:
-            with tracing.span("flush.dispatch"):
-                outs = fn(e.params, jnp.asarray(toks), jnp.asarray(targets),
-                          jnp.asarray(mask))
+        # one batched put (uncommitted without a placement, as the
+        # warm-up's jnp.asarray inputs are, so the same executable
+        # runs); the outputs' host copies are queued behind the step,
+        # so the flush blocks once, in device_get
+        with tracing.span("flush.dispatch"):
+            outs = fn(params, *jax.device_put((toks, targets, mask), dev))
+            for o in outs:
+                o.copy_to_host_async()
         with tracing.span("flush.device"):
-            jax.block_until_ready(outs)
+            host = jax.device_get(outs)
+        self.stats.flush_round_trips += 1
         with tracing.span("flush.fetch"):
-            out = tuple(np.asarray(o)[:n] for o in outs)
+            out = tuple(h[:n] for h in host)
         if self.placement is not None:
             # attribute the flush's (blocked) wall time to its stream —
             # the overlapped-makespan signal bench_mesh scales on

@@ -148,6 +148,10 @@ METRICS: tuple[MetricSpec, ...] = (
     MetricSpec("tryage_padded_rows_total", "counter", (),
                "Wasted rows executed due to bucket padding.",
                "EngineStats.padded_rows"),
+    MetricSpec("tryage_flush_round_trips_total", "counter", (),
+               "Blocking host-device waits made by flushes (one per "
+               "flush: the outputs' fetch).",
+               "EngineStats.flush_round_trips"),
     MetricSpec("tryage_flops_proxy_total", "counter", (),
                "Sum of the 2*params*tokens FLOPs proxy over served "
                "requests.",
@@ -322,6 +326,7 @@ def render(stats, health=None, expert_names: Sequence[str] | None = None
               dict(stats.expert_failures))
     _labelled(w, "tryage_flushes_total", "reason", dict(stats.flushes))
     _scalar(w, "tryage_padded_rows_total", stats.padded_rows)
+    _scalar(w, "tryage_flush_round_trips_total", stats.flush_round_trips)
     _scalar(w, "tryage_flops_proxy_total", stats.total_flops)
     _scalar(w, "tryage_router_time_seconds_total", stats.router_time_s)
     _scalar(w, "tryage_expert_time_seconds_total", stats.expert_time_s)

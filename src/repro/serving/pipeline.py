@@ -462,5 +462,9 @@ class ServingPipeline:
             eng.stats.lane_waits.extend(w for w in waits if w == w)
             if sp:
                 sp.set(uids=[en.req.uid for en in entries], waits=waits)
+            trips = eng.stats.flush_round_trips
             ctx = FlushContext(expert_idx, entries, reason, t)
-            return self.feedback(self.execute(ctx)).results
+            results = self.feedback(self.execute(ctx)).results
+            if sp:
+                sp.set(round_trips=eng.stats.flush_round_trips - trips)
+            return results

@@ -44,6 +44,7 @@ class FakeStats:
         self.expert_failures = {"big": 1}
         self.flushes = {"target": 2, "deadline": 1}
         self.padded_rows = 5
+        self.flush_round_trips = 3
         self.total_flops = 1.5e9
         self.router_time_s = 0.25
         self.expert_time_s = 1.5

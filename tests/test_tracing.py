@@ -141,6 +141,13 @@ def test_every_served_uid_in_exactly_one_flush(traced):
     assert len(set(ids)) == len(ids)
 
 
+def test_each_flush_record_counts_one_round_trip(traced):
+    eng, _, _, recs, _, _ = traced
+    flushes = [r for r in recs if r.name == "flush"]
+    assert [r.attrs["round_trips"] for r in flushes] == [1] * len(flushes)
+    assert eng.stats.flush_round_trips == len(flushes)
+
+
 def test_xspace_mirrors_each_flush_on_the_clock(traced):
     _, _, _, recs, _, trace_dir = traced
     (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
