@@ -1,40 +1,61 @@
-"""The system under test, built from a configuration file and weights:
-the expert library, the router and the engine, and the warm-up of the
-shapes a cell's traffic uses."""
+"""The system under test, built from a configuration file and a seed:
+its weights, the expert library, the router and the engine, and the
+warm-up of the shapes a cell's traffic uses."""
 
 from __future__ import annotations
 
+import functools
+import json
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-
-def model_config(name: str, e: dict, dtype: str):
-    """A ``repro`` encoder config at the file's widths, the way
-    ``repro.core.library._enc`` builds the repo's analogs, in the
-    configuration's ``dtype``."""
-    from repro.models.common import AttnConfig, ModelConfig
-    return ModelConfig(
-        name=name, family="dense", num_layers=e["num_layers"],
-        d_model=e["d_model"], num_heads=e["num_heads"],
-        num_kv_heads=e["num_heads"], d_ff=e["d_ff"],
-        vocab_size=e["vocab_size"],
-        attn=AttnConfig(rope_theta=10000.0, causal=False),
-        layer_pattern=("attn",), moe_pattern=(False,), is_encoder=True,
-        tie_embeddings=True, norm_kind="layernorm", act="gelu", dtype=dtype)
+from bench import router
 
 
-def library(cfg: dict, expert_params: list):
+class _Entry(dict):
+    """A configuration entry as a static argument of a jitted call."""
+
+    def __hash__(self):
+        return hash(json.dumps(self, sort_keys=True))
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _make(seed, router_seed, experts, r, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(seed), len(experts))
+    lib = [draw(k, e, dtype) for k, (draw, e) in zip(ks, experts)]
+    rp = router.weights(jax.random.PRNGKey(router_seed), r, len(experts))
+    return lib, rp
+
+
+def make_weights(cfg: dict, seed: int, archs: list):
+    """Random weights, made on the device in one jitted call in the
+    types they are served in: (list of expert parameter trees from
+    ``seed``, each drawn by its architecture in ``archs``, router
+    parameter tree from ``router.ROUTER_SEED``).  Expert i takes the
+    i-th split of the seed's key."""
+    experts = tuple((a.weights, _Entry(e))
+                    for a, e in zip(archs, cfg["experts"]))
+    return _make(jnp.uint32(seed % 2**32), jnp.uint32(router.ROUTER_SEED),
+                 experts, _Entry(cfg["router"]), jnp.dtype(cfg["dtype"]))
+
+
+def library(cfg: dict, expert_params: list, archs: list):
+    """The expert library, each expert served with the ``ModelConfig``
+    of its architecture in ``archs``."""
     from repro.core.library import ExpertSpec, ModelLibrary
     from repro.data.corpus import DOMAINS
     from repro.models.model import count_params
 
     specs = []
-    for e, p in zip(cfg["experts"], expert_params):
+    for e, p, a in zip(cfg["experts"], expert_params, archs):
         focus = e["focus"]
         mix = {d: (0.2 / len(DOMAINS) + (0.8 / len(focus) if d in focus
                                           else 0.0)) if focus
                else 1.0 / len(DOMAINS) for d in DOMAINS}
         specs.append(ExpertSpec(e["name"],
-                                model_config(e["name"], e, cfg["dtype"]),
+                                a.model_config(e["name"], e, cfg["dtype"]),
                                 mix, e["recency"], source=e["source"],
                                 params=p, n_params=count_params(p)))
     return ModelLibrary(specs)

@@ -9,9 +9,10 @@ Builds the cell once; for each seed swaps in that seed's weights, serves
 a short window of the cell's own traffic, and prints one JSON line with
 the numbers ``harness.check`` compares, read twice on the same sample:
 for the program (the lower readings) and for the control, the plain
-reference put in the program's place one precision below what the
-configuration states, every matrix product of the experts and the
-router in float8 with one scale per tensor (the upper readings).  ``bench/limits/<config>.json`` is set
+references (each expert's architecture's, ``bench/arch/<name>.py``, and
+the router's) put in the program's place one precision below what the
+configuration states, every matrix product in float8 with one scale per
+tensor (the upper readings).  ``bench/limits/<config>.json`` is set
 between the two.  Exits non-zero off a TPU.
 """
 
@@ -41,7 +42,7 @@ def main(argv=None):
     enable_compile_cache()
     _, peaks = harness.require_devices(cell.chips)
     seeds = [int(s) for s in args.seeds.split(",")]
-    system = harness.System(cell.cfg, seeds[0])
+    system = harness.System(cell, seeds[0])
     system.warm(cell.cascade)
     for i, seed in enumerate(seeds):
         if i:

@@ -1,5 +1,6 @@
 """Operations of the served path's device steps, from shapes, and the
-table of chip peaks the shares are taken against.
+table of chip peaks the shares are taken against.  An expert's count is
+its architecture's ``request_flops`` (``bench/arch/<name>.py``).
 
 Only matrix-unit work counts as FLOPs: the projections, the attention
 score and value products, the MLP and the LM head, two FLOPs per
@@ -39,17 +40,6 @@ def encoder_layer_flops(seq: int, d: int, heads: int, head_dim: int,
     attn = 2 * 2 * seq * seq * hd
     mlp = 2 * 2 * seq * d * d_ff
     return proj + attn + mlp
-
-
-def expert_request_flops(e: dict, seq: int) -> int:
-    """FLOPs of one masked-LM expert on one request of ``seq`` tokens:
-    every layer plus the tied LM head over the full vocabulary at every
-    position (``e`` is an expert entry of a configuration file)."""
-    hd = e["d_model"] // e["num_heads"]
-    layers = e["num_layers"] * encoder_layer_flops(
-        seq, e["d_model"], e["num_heads"], hd, e["d_ff"])
-    head = 2 * seq * e["d_model"] * e["vocab_size"]
-    return layers + head
 
 
 def router_request_flops(r: dict, seq: int) -> int:

@@ -5,9 +5,11 @@ counters, host spans and (traced runs) the device trace to metrics.
 
 Everything that belongs to one configuration, traffic mix or metric is
 found by name: ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json``, ``bench/limits/<config>.json`` and
-``bench/metrics/<metric>.py`` (a module with ``read(run)``, returning
-the metric's value or ``None`` where it finds nothing to read).
+``bench/traffic/<traffic>.json``, ``bench/limits/<config>.json``,
+``bench/arch/<arch>.py`` for each expert's architecture (see
+``bench/arch``) and ``bench/metrics/<metric>.py`` (a module with
+``read(run)``, returning the metric's value or ``None`` where it finds
+nothing to read).
 """
 
 from __future__ import annotations
@@ -22,7 +24,7 @@ import time
 
 import numpy as np
 
-from bench import flops, generator, reference
+from bench import arch, flops, generator, reference, router
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 REF_ROWS = 8              # expert reference block
@@ -45,6 +47,7 @@ class Cell:
     cfg: dict
     mix: dict
     limits: dict
+    archs: list                # each expert's architecture module
 
     @classmethod
     def load(cls, root: str, name: str) -> "Cell":
@@ -58,7 +61,8 @@ class Cell:
             root, "bench", "traffic", w["traffic"] + ".json"))
         limits = load_json(os.path.join(root, "bench", "limits",
                                         w["config"] + ".json"))
-        return cls(name, int(w["chips"]), bench, cfg, mix, limits)
+        return cls(name, int(w["chips"]), bench, cfg, mix, limits,
+                   arch.of(cfg, root))
 
     def metrics(self, traced: bool) -> list[dict]:
         key = "per_layer" if traced else "end_to_end"
@@ -81,16 +85,16 @@ def reader(name: str):
 
 
 class System:
-    """The engine over a configuration, built once; ``reseed`` swaps in
-    another seed's weights without recompiling anything."""
+    """The engine over a cell's configuration, built once; ``reseed``
+    swaps in another seed's weights without recompiling anything."""
 
-    def __init__(self, cfg: dict, seed: int):
-        from bench import build, weights
-        self.cfg = cfg
-        self.expert_params, self.router_params = weights.make_weights(
-            cfg, seed)
-        self.lib = build.library(cfg, self.expert_params)
-        self.eng = build.engine(cfg, self.lib, self.router_params)
+    def __init__(self, cell: Cell, seed: int):
+        from bench import build
+        self.cfg, self.archs = cell.cfg, cell.archs
+        self.expert_params, self.router_params = build.make_weights(
+            self.cfg, seed, self.archs)
+        self.lib = build.library(self.cfg, self.expert_params, self.archs)
+        self.eng = build.engine(self.cfg, self.lib, self.router_params)
         self.spans = Spans(self.eng)
         self.compiles = CompileCounter()
 
@@ -99,11 +103,11 @@ class System:
         return build.warm(self.eng, self.cfg, cascade)
 
     def reseed(self, seed: int) -> None:
-        from bench import weights
+        from bench import build
         from repro.core.router import VersionedParams
         from repro.serving.engine import EngineStats
-        self.expert_params, self.router_params = weights.make_weights(
-            self.cfg, seed)
+        self.expert_params, self.router_params = build.make_weights(
+            self.cfg, seed, self.archs)
         for e, p in zip(self.lib.experts, self.expert_params):
             e.params = p
         self.eng._router = VersionedParams(self.router_params, 0)
@@ -275,20 +279,19 @@ def sample_served(results: dict, k: int,
     return sorted(pick)
 
 
-def expert_readings(cfg, expert_params, traffic, results, uids, prec):
+def expert_readings(cfg, archs, expert_params, traffic, results, uids,
+                    prec):
     """Per sampled request: the widest gap of its served tokens below the
     f32 reference's best logit, and the gap of its NLL to the
     reference's; with ``prec`` other than f32 the served tokens and NLL
     are the control's (the reference in that precision), not the
-    program's."""
-    names = [e["name"] for e in cfg["experts"]]
+    program's.  Each expert is read by its architecture's ``readings``."""
     reqs = traffic.requests
     gaps, nll_gaps = [], []
-    for ei, name in enumerate(names):
-        mine = [u for u in uids if results[u].expert == name]
+    for e, a, p in zip(cfg["experts"], archs, expert_params):
+        mine = [u for u in uids if results[u].expert == e["name"]]
         if not mine:
             continue
-        p = expert_params[ei]
         toks = np.stack([reqs[u].tokens for u in mine])
         tgt = np.stack([reqs[u].targets for u in mine]).astype(np.int32)
         msk = np.stack([reqs[u].mask for u in mine]).astype(np.int32)
@@ -298,18 +301,18 @@ def expert_readings(cfg, expert_params, traffic, results, uids, prec):
                              else results[u].loss for u in mine])
         else:
             _, loss, served = reference.in_blocks(
-                lambda t, s, g, m: reference.expert_readings(
-                    p, t, s, g, m, prec=prec), REF_ROWS, toks,
-                np.zeros_like(toks), tgt, msk)
+                lambda t, s, g, m: a.readings(p, e, t, s, g, m, prec),
+                REF_ROWS, toks, np.zeros_like(toks), tgt, msk)
         gap, nll, _ = reference.in_blocks(
-            lambda t, s, g, m: reference.expert_readings(p, t, s, g, m),
+            lambda t, s, g, m: a.readings(p, e, t, s, g, m, "f32"),
             REF_ROWS, toks, served.astype(np.int32), tgt, msk)
         gaps.append(gap.max(axis=1))
         nll_gaps.append(np.abs(loss - nll) / np.maximum(1.0, np.abs(nll)))
     return np.concatenate(gaps), np.concatenate(nll_gaps)
 
 
-def router_readings(cfg, router_params, traffic, results, uids, prec):
+def router_readings(cfg, archs, router_params, traffic, results, uids,
+                    prec):
     """Per sampled request: the largest gap of predicted losses to the
     f32 reference's, and the verdict margin (0 where the verdict is the
     reference's; else the least margin on the reference's own way to
@@ -318,12 +321,11 @@ def router_readings(cfg, router_params, traffic, results, uids, prec):
     reqs = traffic.requests
     toks = np.stack([reqs[u].tokens for u in uids])
     pred_ref, sig_ref = reference.in_blocks(
-        lambda t: reference.router_readings(router_params, t), ROUTER_ROWS,
-        toks)
-    costs = reference.constraint_costs(cfg)
+        lambda t: router.readings(router_params, t), ROUTER_ROWS, toks)
+    costs = reference.constraint_costs(cfg, archs)
     lam = np.array([[reqs[u].lambdas.get(c, 0.0) for c in cfg["constraints"]]
                     for u in uids])
-    order = reference.ladder(cfg)
+    order = reference.ladder(cfg, archs)
     depth = cfg["engine"]["cascade_max_depth"]
     names = [e["name"] for e in cfg["experts"]]
     if prec == "f32":
@@ -331,8 +333,7 @@ def router_readings(cfg, router_params, traffic, results, uids, prec):
         final = np.array([names.index(results[u].expert) for u in uids])
     else:
         pred, sig = reference.in_blocks(
-            lambda t: reference.router_readings(router_params, t,
-                                                prec=prec),
+            lambda t: router.readings(router_params, t, prec=prec),
             ROUTER_ROWS, toks)
         final = np.array([reference.verdict(
             pred[i] + lam[i] @ costs, 1.0 / (1.0 + sig[i]),
@@ -358,10 +359,12 @@ def readings(system: System, traffic, results, seed: int,
     rng = np.random.default_rng([seed, 7])
     ex = sample_served(results, EXPERT_SAMPLE, rng)
     ro = sample_served(results, ROUTER_SAMPLE, rng)
-    tok_gap, nll_gap = expert_readings(system.cfg, system.expert_params,
-                                       traffic, results, ex, expert_prec)
-    pred_gap, margins = router_readings(system.cfg, system.router_params,
-                                        traffic, results, ro, router_prec)
+    tok_gap, nll_gap = expert_readings(system.cfg, system.archs,
+                                       system.expert_params, traffic,
+                                       results, ex, expert_prec)
+    pred_gap, margins = router_readings(system.cfg, system.archs,
+                                        system.router_params, traffic,
+                                        results, ro, router_prec)
     return {"token_logit_gap": float(np.max(tok_gap)),
             "nll_rel_gap": float(np.nanmax(nll_gap)),
             "pred_loss_gap": float(np.max(pred_gap)),

@@ -1,6 +1,9 @@
-"""Plain references for the output check, written from the published
-descriptions and the configuration files, importing nothing of the
-program.
+"""Helpers of the plain references for the output check, importing
+nothing of the program: the matrix products in each precision, the
+norms, RoPE and the tied masked-LM head that the router's reference
+(``bench/router.py``) and every expert architecture's
+(``bench/arch/<name>.py``) are written with; the objective's verdict
+and constraint costs; and the blocked application of a reference.
 
 Precision.  ``"f32"`` is the reference: float32 weights (bf16 weights
 widened exactly), float32 activations and every matrix product at
@@ -12,22 +15,10 @@ in for the program to show the check can fail:
   activations stored in bfloat16 (the control of the experts, which the
   configuration states in bfloat16, and of the router, whose float32
   products run at the TPU's default precision as one bfloat16 pass).
-
-Architecture (both the experts and the router's encoder): token
-embedding; per layer, pre-LayerNorm (eps 1e-6) multi-head attention,
-bidirectional, with RoPE (theta 10000, rotate-half on the two halves
-of each head) on queries and keys, then a pre-LayerNorm two-matrix MLP
-with tanh-GELU, each added to the residual; a final LayerNorm.  An
-expert's logits are the final states times the tied embedding.  The
-router mean-pools its final states over non-pad ids (id 0 is pad),
-and its loss head and uncertainty head are each
-``softplus(gelu(e W1 + b1) W2 + b2)``, the latter plus 1e-3.
-Weights use the layout the benchmark makes (``bench/weights.py``).
 """
 
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -37,7 +28,6 @@ import numpy as np
 HI = jax.lax.Precision.HIGHEST
 FP8_MAX = 448.0
 LN_EPS = 1e-6
-UNC_FLOOR = 1e-3
 
 
 def _q8(x):
@@ -47,7 +37,7 @@ def _q8(x):
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(spec: str, prec: str, a, b):
+def mm(spec: str, prec: str, a, b):
     """einsum under the given precision; result in the activation type."""
     if prec == "f32":
         return jnp.einsum(spec, a.astype(jnp.float32),
@@ -59,19 +49,21 @@ def _mm(spec: str, prec: str, a, b):
     return out.astype(jnp.bfloat16)
 
 
-def _act(prec: str):
+def act(prec: str):
+    """The activation type of ``prec``."""
     return jnp.float32 if prec == "f32" else jnp.bfloat16
 
 
-def _ln(p, x, prec):
+def layer_norm(p, x, prec):
+    """LayerNorm (eps 1e-6) with scale and bias, in ``act(prec)``."""
     xf = x.astype(jnp.float32)
     mu = xf.mean(-1, keepdims=True)
     var = jnp.square(xf - mu).mean(-1, keepdims=True)
     y = (xf - mu) / jnp.sqrt(var + LN_EPS) * p["scale"] + p["bias"]
-    return y.astype(_act(prec))
+    return y.astype(act(prec))
 
 
-def _rope(x, theta=10000.0):
+def rope(x, theta=10000.0):
     """x (B, S, H, D): rotate the halves (x1, x2) of each head by the
     angle position * theta^(-2i/D)."""
     S, D = x.shape[1], x.shape[-1]
@@ -83,38 +75,12 @@ def _rope(x, theta=10000.0):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(p, x, prec):
-    a = _act(prec)
-    h = _ln(p["norm1"], x, prec)
-    q = _rope(_mm("bsd,dhk->bshk", prec, h, p["mix"]["wq"]))
-    k = _rope(_mm("bsd,dhk->bshk", prec, h, p["mix"]["wk"]))
-    v = _mm("bsd,dhk->bshk", prec, h, p["mix"]["wv"])
-    s = _mm("bshk,bthk->bhst", prec, q.astype(a), k.astype(a))
-    s = s.astype(jnp.float32) / math.sqrt(q.shape[-1])
-    w = jax.nn.softmax(s, axis=-1)
-    o = _mm("bhst,bthk->bshk", prec, w.astype(a), v)
-    x = (x + _mm("bshk,hkd->bsd", prec, o, p["mix"]["wo"])).astype(a)
-    h = _ln(p["norm2"], x, prec)
-    u = _mm("bsd,df->bsf", prec, h, p["mlp"]["wi"])
-    u = jax.nn.gelu(u.astype(jnp.float32), approximate=True).astype(a)
-    return (x + _mm("bsf,fd->bsd", prec, u, p["mlp"]["wo"])).astype(a)
-
-
-def encoder_states(p, tokens, prec: str):
-    """Final-LayerNorm hidden states (B, S, d) of an encoder."""
-    x = p["embed"]["table"][tokens].astype(_act(prec))
-    x, _ = jax.lax.scan(lambda h, lp: (_layer(lp, h, prec), None), x,
-                        p["units"]["l0"])
-    return _ln(p["final_norm"], x, prec)
-
-
-@functools.partial(jax.jit, static_argnames="prec")
-def expert_readings(p, tokens, served, targets, mask, prec="f32"):
-    """Per row: the reference's best logit minus its logit of each
-    served token (B, S), its masked NLL (B,), and the token it puts
-    first (B, S).  ``served`` are the tokens whose gap is read."""
-    h = encoder_states(p, tokens, prec)
-    logits = _mm("bsd,vd->bsv", prec, h, p["embed"]["table"])
+def mlm_readings(h, table, served, targets, mask, prec):
+    """Per row, from final states ``h`` (B, S, d) and the tied
+    embedding ``table``: the best logit minus the logit of each served
+    token (B, S), the masked NLL (B,), and the token put first (B, S).
+    ``served`` are the tokens whose gap is read."""
+    logits = mm("bsd,vd->bsv", prec, h, table)
     logits = logits.astype(jnp.float32)
     best = logits.max(-1)
     got = jnp.take_along_axis(logits, served[..., None], -1)[..., 0]
@@ -123,23 +89,6 @@ def expert_readings(p, tokens, served, targets, mask, prec="f32"):
     m = mask.astype(jnp.float32)
     return (best - got, (nll * m).sum(-1) / jnp.maximum(m.sum(-1), 1.0),
             logits.argmax(-1).astype(jnp.int32))
-
-
-def _head(p, e, prec):
-    h = _mm("bd,dh->bh", prec, e, p["w1"]).astype(jnp.float32) + p["b1"]
-    h = jax.nn.gelu(h, approximate=True).astype(_act(prec))
-    raw = _mm("bh,hm->bm", prec, h, p["w2"]).astype(jnp.float32) + p["b2"]
-    return jax.nn.softplus(raw)
-
-
-@functools.partial(jax.jit, static_argnames="prec")
-def router_readings(rp, tokens, prec="f32"):
-    """Predicted losses and uncertainties (B, M), both f32."""
-    h = encoder_states(rp["encoder"], tokens, prec).astype(jnp.float32)
-    valid = (tokens != 0).astype(jnp.float32)[..., None]
-    e = (h * valid).sum(1) / jnp.maximum(valid.sum(1), 1.0)
-    e = e.astype(_act(prec))
-    return _head(rp["head"], e, prec), _head(rp["unc"], e, prec) + UNC_FLOOR
 
 
 def in_blocks(fn, rows: int, *arrays):
@@ -161,28 +110,24 @@ def in_blocks(fn, rows: int, *arrays):
 
 # ------------------------------------------------------------ routing
 
-def expert_param_count(e: dict) -> int:
-    """Parameters of one expert from its widths: embedding, per layer
-    two LayerNorms and the attention and MLP matrices, a final
-    LayerNorm."""
-    d, ff, V = e["d_model"], e["d_ff"], e["vocab_size"]
-    per_layer = 2 * 2 * d + 4 * d * d + 2 * d * ff
-    return V * d + e["num_layers"] * per_layer + 2 * d
+def param_counts(cfg: dict, archs: list) -> list[int]:
+    """Each expert's parameters, as its architecture counts them."""
+    return [a.param_count(e) for a, e in zip(archs, cfg["experts"])]
 
 
-def constraint_costs(cfg: dict) -> np.ndarray:
+def constraint_costs(cfg: dict, archs: list) -> np.ndarray:
     """(n_c, M) costs of the configuration's constraints: ``size`` is
     parameters over the largest expert's, ``recency`` is 1 - recency."""
-    sizes = np.array([expert_param_count(e) for e in cfg["experts"]], float)
+    sizes = np.array(param_counts(cfg, archs), float)
     table = {"size": sizes / sizes.max(),
              "recency": 1.0 - np.array([e["recency"]
                                         for e in cfg["experts"]], float)}
     return np.stack([table[c] for c in cfg["constraints"]])
 
 
-def ladder(cfg: dict) -> list[int]:
+def ladder(cfg: dict, archs: list) -> list[int]:
     """Experts by ascending parameter count, ties in library order."""
-    sizes = [expert_param_count(e) for e in cfg["experts"]]
+    sizes = param_counts(cfg, archs)
     return sorted(range(len(sizes)), key=lambda i: (sizes[i], i))
 
 

@@ -62,7 +62,7 @@ def main(argv=None, *, root=ROOT, require_tpu=True, peaks=None,
     else:
         devs = jax.devices()[:cell.chips]
 
-    system = harness.System(cell.cfg, args.seed)
+    system = harness.System(cell, args.seed)
     if fault is not None:
         fault(system)
     n_programs = system.warm(cell.cascade)

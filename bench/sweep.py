@@ -43,7 +43,7 @@ def main(argv=None):
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     devs, peaks = harness.require_devices(cell.chips)
-    system = harness.System(cell.cfg, args.seed)
+    system = harness.System(cell, args.seed)
     t = time.monotonic()
     n = system.warm(cell.cascade)
     print(json.dumps({"setup_s": time.monotonic() - T_START,

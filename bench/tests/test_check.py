@@ -28,11 +28,13 @@ def run(cell, seed, fault=None, trace=0):
                          fault=fault)
 
 
-def _wrap_experts(system, change):
-    """Replace every expert step by ``change`` applied around it."""
+def _wrap_experts(system, change, only=None):
+    """Replace every expert step (or expert ``only``'s) by ``change``
+    applied around it."""
     eng = system.eng
     for name, fn in list(eng._expert_fns.items()):
-        eng._expert_fns[name] = jax.jit(change(fn))
+        if only in (None, name):
+            eng._expert_fns[name] = jax.jit(change(fn))
 
 
 def token_altered(system):
@@ -44,16 +46,18 @@ def token_altered(system):
     _wrap_experts(system, change)
 
 
+def _first_half_answers(fn):
+    def step(p, toks, targets, mask):
+        half = (toks.shape[0] + 1) // 2
+        take = jnp.arange(toks.shape[0]) % half
+        return fn(p, toks[take], targets[take], mask[take])
+    return step
+
+
 def half_batch_left_out(system):
     """The second half of every flush is computed from the first half's
     rows: those requests get another prompt's answer."""
-    def change(fn):
-        def step(p, toks, targets, mask):
-            half = (toks.shape[0] + 1) // 2
-            take = jnp.arange(toks.shape[0]) % half
-            return fn(p, toks[take], targets[take], mask[take])
-        return step
-    _wrap_experts(system, change)
+    _wrap_experts(system, _first_half_answers)
 
 
 def verdict_altered(system):
@@ -93,7 +97,8 @@ def test_sound_run_is_correct():
     assert out["failed"] == 0 and out["attempted"] == 400
 
 
-@pytest.mark.parametrize("cell", ["tiny-steady", "tiny-cascade"])
+@pytest.mark.parametrize("cell", ["tiny-steady", "tiny-cascade",
+                                  "tiny-mixed-steady"])
 @pytest.mark.parametrize("fault", [token_altered, half_batch_left_out,
                                    verdict_altered, request_dropped],
                          ids=lambda f: f.__name__)
@@ -102,12 +107,42 @@ def test_fault(cell, fault):
     assert not out["correct"], out["checks"]
 
 
+def every_token_altered_in_g(system):
+    """Expert ``g`` alone (the test architecture ``swiglu_gqa``) alters
+    one token of every row it serves; the other experts are sound."""
+    def change(fn):
+        def step(p, toks, targets, mask):
+            preds, loss, acc = fn(p, toks, targets, mask)
+            return preds.at[:, 5].add(1), loss, acc
+        return step
+    _wrap_experts(system, change, only="g")
+
+
+def half_batch_left_out_in_g(system):
+    """Expert ``g`` alone answers the second half of each flush from
+    the first half's rows."""
+    _wrap_experts(system, _first_half_answers, only="g")
+
+
+@pytest.mark.parametrize("fault", [every_token_altered_in_g,
+                                   half_batch_left_out_in_g],
+                         ids=lambda f: f.__name__)
+def test_fault_in_other_arch_alone(fault):
+    """A fault in the expert of the new architecture alone is caught by
+    the readings of that expert's requests."""
+    out = run("tiny-mixed-steady", 2**31 + 13, fault=fault)
+    assert not out["correct"], out["checks"]
+    assert out["checks"]["missing_failed_or_duplicate"]["value"] == 0
+    assert any(out["checks"][k]["value"] > out["checks"][k]["limit"]
+               for k in ("token_logit_gap", "nll_rel_gap")), out["checks"]
+
+
 def test_control_fails():
     """On three seeds, the control (experts and router in float8)
     fails one of the cell's numbers against the limits the
     program's own runs pass."""
     cell = harness.Cell.load(DATA, "tiny-cascade")
-    system = harness.System(cell.cfg, 21)
+    system = harness.System(cell, 21)
     system.warm(cell.cascade)
     for seed in (21, 22, 23):
         if seed != 21:
@@ -129,13 +164,14 @@ def test_control_fails():
 def test_reference_matches_program_in_float32():
     """At float32 the program's expert step and the reference agree to
     rounding: the reference is the same model."""
-    from bench import build, reference, weights
+    from bench import arch, build
     cfg = harness.load_json(os.path.join(DATA, "bench", "configs",
                                          "tiny.json"))
     cfg = dict(cfg, dtype="float32")
-    params, _ = weights.make_weights(cfg, 5)
+    archs = arch.of(cfg, DATA)
+    params, _ = build.make_weights(cfg, 5, archs)
     e = cfg["experts"][2]
-    mc = build.model_config(e["name"], e, "float32")
+    mc = archs[2].model_config(e["name"], e, "float32")
     from repro.serving.engine import TryageEngine
     rng = np.random.default_rng(0)
     toks = rng.integers(4, 512, size=(4, 16)).astype(np.int32)
@@ -143,8 +179,8 @@ def test_reference_matches_program_in_float32():
     with jax.default_matmul_precision("highest"):
         preds, loss, _ = TryageEngine._expert_forward(
             params[2], toks, toks, mask, cfg=mc)
-    gap, nll, first = reference.expert_readings(
-        params[2], toks, np.asarray(preds), toks, mask)
+    gap, nll, first = archs[2].readings(
+        params[2], e, toks, np.asarray(preds), toks, mask)
     assert np.array_equal(np.asarray(preds), np.asarray(first))
     assert np.max(np.asarray(gap)) == 0.0
     np.testing.assert_allclose(np.asarray(loss), np.asarray(nll),

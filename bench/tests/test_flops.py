@@ -1,8 +1,13 @@
 """FLOP counts against hand counts."""
 
+import os
+
 import pytest
 
-from bench import flops
+from bench import arch, flops
+
+ENCODER = arch.load("encoder")
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
 BERT_BASE = {"num_layers": 12, "d_model": 768, "num_heads": 12,
              "d_ff": 3072, "vocab_size": 30522}
@@ -16,11 +21,11 @@ def test_bert_base_request_matches_hand_count():
     head = 2 * 768 * 30522
     per_token = 12 * per_layer + head
     assert per_token == 221_469_696
-    assert flops.expert_request_flops(BERT_BASE, 128) == 128 * per_token
+    assert ENCODER.request_flops(BERT_BASE, 128) == 128 * per_token
 
 
 def test_bert_base_bucket_of_eight():
-    assert 8 * flops.expert_request_flops(BERT_BASE, 128) \
+    assert 8 * ENCODER.request_flops(BERT_BASE, 128) \
         == 226_784_968_704
 
 
@@ -42,3 +47,16 @@ def test_unknown_device_kind_is_an_error():
     assert flops.peaks_for("TPU v5 lite")["bf16_flops"] == 197e12
     with pytest.raises(KeyError):
         flops.peaks_for("TPU v9 imaginary")
+
+
+def test_other_arch_request_matches_hand_count():
+    """The test-only SwiGLU/GQA architecture: Q and output projections
+    over 4 heads, K and V over 2, attention over 16 keys, three MLP
+    matrices, and the tied head."""
+    e = {"num_layers": 2, "d_model": 32, "num_heads": 4, "num_kv_heads": 2,
+         "d_ff": 48, "vocab_size": 520}
+    per_layer = (2 * 32 * (4 + 4) * 8 + 2 * 32 * (2 + 2) * 8
+                 + 4 * 16 * 32 + 3 * 2 * 32 * 48)
+    per_token = 2 * per_layer + 2 * 32 * 520
+    assert arch.load("swiglu_gqa", DATA).request_flops(e, 16) \
+        == 16 * per_token == 1_089_536
